@@ -25,18 +25,16 @@ from __future__ import annotations
 
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-import jax                                                     # noqa: E402
-import jax.numpy as jnp                                        # noqa: E402
-import numpy as np                                             # noqa: E402
-
-from benchmarks.common import timeit, write_bench_json         # noqa: E402
-from repro.configs.registry import get_config                  # noqa: E402
-from repro.core import medusa as M                             # noqa: E402
-from repro.core.engine import build_engine                     # noqa: E402
-from repro.distributed.sharding import split_params            # noqa: E402
-from repro.models.api import get_model, init_cache             # noqa: E402
+from benchmarks.common import timeit, write_bench_json
+from repro.configs.registry import get_config
+from repro.core import medusa as M
+from repro.core.engine import build_engine
+from repro.distributed.sharding import split_params
+from repro.models.api import get_model, init_cache
 
 B, PROMPT, NEW, SEQ_KV = 2, 24, 16, 4096
 
@@ -208,6 +206,11 @@ def run(smoke: bool = False):
 
 if __name__ == "__main__":
     import argparse
+    # 8 forced host devices for the CPU identity rows — set only when run
+    # as a script, before the first backend use (importing jax starts none),
+    # so importing this module never changes another program's devices
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args()

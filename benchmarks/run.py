@@ -15,6 +15,9 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_families, bench_fig34_speedup,
                             bench_kv_quant, bench_prefix_cache,
                             bench_proposers, bench_sampling, bench_serving,

@@ -28,8 +28,13 @@ def init_medusa(key, cfg: ModelConfig, K: int, base_lm_head=None, dtype=None):
     if base_lm_head is not None:
         lm = jnp.broadcast_to(base_lm_head.astype(dt)[None], (K, d, V)) + 0
     else:
-        lm = jnp.stack([jax.random.normal(k, (d, V), dt) / jnp.sqrt(d * 1.0)
-                        for k in ks])
+        # one head at a time into the stacked buffer: drawing all K at once
+        # (stack or vmap) holds a second [K, d, V] copy at the peak — 5 GB
+        # more than the heads themselves at a 150k vocab
+        lm = jnp.zeros((K, d, V), dt)
+        for i, k in enumerate(ks):
+            lm = lm.at[i].set(jax.random.normal(k, (d, V), dt)
+                              / jnp.sqrt(d * 1.0))
     return {
         # zero init => resblock starts as identity
         "w1": Param(jnp.zeros((K, d, d), dt), ("medusa", "embed", "medusa_ff")),

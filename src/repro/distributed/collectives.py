@@ -14,28 +14,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5: promoted to the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.3x: pre-promotion home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect
-
-# the replication-check kwarg was renamed (check_rep -> check_vma) across
-# the promotion; resolve whichever this jax build understands
-_CHECK_KW = next((k for k in ("check_vma", "check_rep")
-                  if k in inspect.signature(_shard_map).parameters), None)
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """Version-compat ``shard_map``: one call site syntax for jax 0.4.3x
-    (``jax.experimental.shard_map``, ``check_rep``) and newer jax
-    (``jax.shard_map``, ``check_vma``)."""
-    kw = {_CHECK_KW: check} if _CHECK_KW else {}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    """``jax.shard_map`` with its replication check (``check_vma``) as one
+    boolean — the single call-site syntax every shard_map in the repo uses
+    (and the name speclint's shard-specs rule keys on)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def ag_matmul_local(x_loc, w, axis_name: str):

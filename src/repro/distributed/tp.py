@@ -49,9 +49,9 @@ _TP_PROPOSERS = ("medusa", "ngram")
 def make_tp_mesh(tp: int, data: int = 1) -> Mesh:
     """("data", "model") mesh over the first ``data * tp`` local devices.
 
-    CI materialises the devices with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before importing
-    jax (the forced-host CPU mesh the §18 identity tests run on)."""
+    On a TPU host these are its chips; the CPU tests materialise devices
+    with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before
+    importing jax (the forced-host mesh the §18 identity tests run on)."""
     n = data * tp
     devs = jax.devices()
     if len(devs) < n:
@@ -126,9 +126,11 @@ class TPSpecEngine:
 
     # ------------------------------------------------------------ placement
 
-    def shard_params(self, params, axes):
-        """Place a ``split_params`` (values, axes) pair onto the mesh per
-        the TP plan and remember the spec tree for the wrapped calls."""
+    def param_specs(self, params, axes):
+        """PartitionSpec tree of the TP plan for a ``split_params`` (values,
+        axes) pair — values may be arrays or ``ShapeDtypeStruct``s, so the
+        plan exists before any weight does — remembered for the wrapped
+        calls."""
         rules = {"heads": self.axis, "kv_heads": self.axis,
                  "ff": self.axis, "vocab": self.axis}
 
@@ -145,6 +147,12 @@ class TPSpecEngine:
             # global-token-id take, replicated on purpose (DESIGN.md §18)
             specs["embed"] = P()
         self._pspecs = specs
+        return specs
+
+    def shard_params(self, params, axes):
+        """Place a ``split_params`` (values, axes) pair onto the mesh per
+        the TP plan and remember the spec tree for the wrapped calls."""
+        specs = self.param_specs(params, axes)
         return jax.device_put(params, profiles.to_named(specs, self.mesh))
 
     def shard_cache(self, cache):
@@ -200,6 +208,26 @@ class TPSpecEngine:
 
         return self._cached("prefill", build)(
             params, proposer_params, tokens, lengths, cache, key, state)
+
+    def prefill_logits(self, params, tokens, lengths, cache):
+        """[B, V] next-token logits after each prompt, replicated — the
+        sharded prefill's output before any proposer or verify step (what
+        a single-device prefill is compared against)."""
+        pspecs, local = self._require_specs(), self.local
+        cspec = profiles.tp_cache_pspecs(cache, self.cfg, self.mesh,
+                                         self.axis)
+
+        def build():
+            def logits_fn(params, tokens, lengths, cache):
+                h, _ = local.model.prefill(params, local.cfg, tokens,
+                                           lengths, cache)
+                return local.model.unembed(params, local.cfg, h)
+            return jax.jit(shard_map_compat(
+                logits_fn, mesh=self.mesh, in_specs=(pspecs, P(), P(), cspec),
+                out_specs=P(), check=False))
+
+        return self._cached("prefill_logits", build)(params, tokens, lengths,
+                                                     cache)
 
     def spec_step(self, params, proposer_params, cache, lengths, base, state,
                   key):

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_default
+
 
 def _kernel(lens_ref, rows_ref, cache_ref, out_ref, sem, *, K1: int):
     b = pl.program_id(0)
@@ -35,7 +37,7 @@ def commit_rows(cache, rows, lengths, *, interpret: bool | None = None):
     [lengths[b], lengths[b]+K1) in place via per-row async DMA; returns
     cache.  Traffic is O(K1 rows), not O(cache)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     B, S, H, D = cache.shape
     K1 = rows.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -96,7 +98,7 @@ def commit_rows_paged(pool, block_tables, rows, lengths, *,
     straddle a block boundary), still O(K1 rows) of traffic.  Rows beyond
     the table's reach sink into reserved block 0.  Returns pool."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     n_blocks, ps, H, D = pool.shape
     B, K1 = rows.shape[:2]
     mb = block_tables.shape[1]
@@ -225,7 +227,7 @@ def fused_qkv_rope_commit(x, p, lengths, k_cache, v_cache, *, cos=None,
     The fp-only fast path: int8 caches keep the unfused projection (the
     quantize hop needs the scale cache — DESIGN.md §10)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     B, T, d = x.shape
     Hq, hd = p["wq"].shape[1:]
     Hkv = p["wk"].shape[1]

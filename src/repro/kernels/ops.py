@@ -5,15 +5,16 @@ partial-softmax stats.
 Accepts both cache dtypes (DESIGN.md §10): fp k/v, or int8 k/v with
 per-head-per-row f32 scales — and both cache layouts (DESIGN.md §12):
 dense per-slot rows, or the paged block pool addressed through per-slot
-``block_tables``.  On non-TPU backends the kernel runs in interpret mode
-(tests); the jnp tree block and the merge are backend-agnostic.
+``block_tables``.  On the CPU backend the kernel runs in interpret mode
+(tests; ``kernels.platform``); the jnp tree block and the merge are
+backend-agnostic.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import quant as Q
+from repro.kernels.platform import interpret_default
 from repro.kernels.tree_attention import flash_decode, unembed_verify_stats
 
 
@@ -24,10 +25,10 @@ def verify_stats(hidden, w, candidates, tmax, *, block_v=None,
     hidden [B, T, d]; w [d, V] lm-head weight (cast to hidden.dtype like
     ``models.transformer.unembed``); candidates [B, T] int32; tmax [B] f32
     pre-clamped warp temperatures.  Returns (argm, m, l, cand_w) — see
-    ``kernels.tree_attention.unembed_verify_stats``.  On non-TPU backends
-    the kernel runs in interpret mode (tests)."""
+    ``kernels.tree_attention.unembed_verify_stats``.  On the CPU backend
+    the kernel runs in interpret mode (tests; ``kernels.platform``)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     return unembed_verify_stats(hidden, w, candidates, tmax,
                                 block_v=block_v, interpret=interpret)
 
@@ -69,7 +70,7 @@ def tree_attention(q, k, v, tree_mask, lengths, scale, *,
     G = Hq // Hkv
     quantized = k.dtype == jnp.int8
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     # tiny/odd caches fall through to flash_decode's pad/clamp path
     bs = None if paged else (block_s or _pick_block(S) or 128)

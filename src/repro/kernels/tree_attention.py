@@ -232,11 +232,13 @@ def flash_decode(q, k, v, lengths, *, k_scale=None, v_scale=None,
 # fused verify epilogue: unembed + acceptance statistics (DESIGN.md §15)
 # ---------------------------------------------------------------------------
 
-def _verify_stats_kernel(tmax_ref, cand_ref,   # scalar prefetch [B] f32, [B,T] i32
-                         h_ref, w_ref,         # VMEM blocks [1,T,d], [d,BV]
+def _verify_stats_kernel(tmax_ref,             # scalar prefetch [B] f32
+                         cand_ref, h_ref, w_ref,  # [1,1,T] i32, [1,T,d],
+                                                  # [d,BV] (or [BV,d])
                          argm_ref, m_ref, l_ref, cl_ref,
                          wmax_scr, lsum_scr, amax_scr, cl_scr,
-                         *, block_v: int, n_v: int, V: int, T: int):
+                         *, block_v: int, n_v: int, V: int, T: int,
+                         vocab_major: bool):
     """One (b, j) grid step of the vocab sweep.
 
     Streams the lm-head matmul over vocab blocks and keeps only the
@@ -245,7 +247,10 @@ def _verify_stats_kernel(tmax_ref, cand_ref,   # scalar prefetch [B] f32, [B,T] 
     sum-exp ``l`` (online softmax carry), and the [T, T] candidate-logit
     table extracted by a one-hot matmul — exact, because each output element
     is one ``x * 1`` plus exact zeros.  The full [T, BV] logits block dies
-    in VMEM; nothing [*, V]-shaped reaches HBM.
+    in VMEM; nothing [*, V]-shaped reaches HBM.  The last vocab block may
+    run past V: its out-of-range columns hold unspecified values and are
+    masked to NEG_INF before any statistic reads them.  ``vocab_major``
+    blocks arrive as [BV, d] rows of the transposed head.
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -259,7 +264,7 @@ def _verify_stats_kernel(tmax_ref, cand_ref,   # scalar prefetch [B] f32, [B,T] 
 
     h = h_ref[0]                                   # [T, d]
     z = jax.lax.dot_general(
-        h, w_ref[...], (((1,), (0,)), ((), ())),
+        h, w_ref[...], (((1,), (1 if vocab_major else 0,)), ((), ())),
         preferred_element_type=jnp.float32)        # [T, BV]
     # round through the activation dtype (bf16 configs) so the stats match
     # the unfused ``unembed`` einsum, then warp exactly as
@@ -280,18 +285,19 @@ def _verify_stats_kernel(tmax_ref, cand_ref,   # scalar prefetch [B] f32, [B,T] 
         jnp.exp(wv - m_new), axis=1, keepdims=True)
     wmax_scr[...] = m_new
 
-    rel = cand_ref[b][None, :] - j * block_v       # [1, T]
+    rel = cand_ref[0] - j * block_v                # [1, T]
     onehot = (jax.lax.broadcasted_iota(jnp.int32, (block_v, T), 0)
               == rel).astype(jnp.float32)          # [BV, T]
     cl_scr[...] += jax.lax.dot_general(
         wv, onehot, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)        # [T, T]
 
     @pl.when(j == n_v - 1)
     def _emit():
-        argm_ref[0] = amax_scr[...][:, 0]
-        m_ref[0] = wmax_scr[...][:, 0]
-        l_ref[0] = lsum_scr[...][:, 0]
+        argm_ref[0] = amax_scr[...]
+        m_ref[0] = wmax_scr[...]
+        l_ref[0] = lsum_scr[...]
         cl_ref[0] = cl_scr[...]
 
 
@@ -309,6 +315,17 @@ def unembed_verify_stats(hidden, w, candidates, tmax, *, block_v=None,
     match and the residual-mass walk need, at O(T^2) instead of O(T*V)
     HBM traffic.
 
+    The vocab sweep runs ``cdiv(V, block_v)`` blocks straight off ``w``: a
+    V that is no multiple of ``block_v`` (openpangu's 153,376) leaves a
+    ragged last block that the kernel masks, so the lm head is never
+    padded.  Nor is it relaid out: XLA's TPU layout keeps a 2-D array's
+    128-aligned dim minor, so a [d, V] head with V % 128 != 0 sits in HBM
+    as its [V, d] transpose.  The kernel then reads ``w.T`` (a free
+    bitcast) in [block_v, d] blocks instead of forcing a row-major copy of
+    the whole head into every call.  Per-row outputs are laid out
+    [B, T, 1] so every block's last two dims are (T, 1) — a multiple of 8
+    and the full lane dim — which the TPU lowering requires for any B.
+
     When the vocab fits one block (the default for V <= 4096) the online
     carry degenerates to a single pass and ``exp(cand_w - m) / l`` is
     bitwise ``softmax(warped)`` gathered at the candidates; with multiple
@@ -323,26 +340,27 @@ def unembed_verify_stats(hidden, w, candidates, tmax, *, block_v=None,
         candidates = jnp.pad(candidates, ((0, 0), (0, T_pad)))
     Tp = T + T_pad
     if block_v is None:
-        block_v = V if V <= 4096 else 1024
+        block_v = V if V <= 4096 else 512
     block_v = max(-(-block_v // 128) * 128, 128)
-    pad_v = (-V) % block_v
-    if pad_v:
-        w = jnp.pad(w, ((0, 0), (0, pad_v)))
-    n_v = (V + pad_v) // block_v
+    n_v = pl.cdiv(V, block_v)
+    vocab_major = bool(V % 128) and not d % 128
+    if vocab_major:
+        w = w.T
+        w_spec = pl.BlockSpec((block_v, d), lambda b, j, tm: (j, 0))
+    else:
+        w_spec = pl.BlockSpec((d, block_v), lambda b, j, tm: (0, j))
 
+    row = pl.BlockSpec((1, Tp, 1), lambda b, j, tm: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(B, n_v),
         in_specs=[
-            pl.BlockSpec((1, Tp, d), lambda b, j, tm, cd: (b, 0, 0)),
-            pl.BlockSpec((d, block_v), lambda b, j, tm, cd: (0, j)),
+            pl.BlockSpec((1, 1, Tp), lambda b, j, tm: (b, 0, 0)),
+            pl.BlockSpec((1, Tp, d), lambda b, j, tm: (b, 0, 0)),
+            w_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((1, Tp), lambda b, j, tm, cd: (b, 0)),
-            pl.BlockSpec((1, Tp), lambda b, j, tm, cd: (b, 0)),
-            pl.BlockSpec((1, Tp), lambda b, j, tm, cd: (b, 0)),
-            pl.BlockSpec((1, Tp, Tp), lambda b, j, tm, cd: (b, 0, 0)),
-        ],
+        out_specs=[row, row, row,
+                   pl.BlockSpec((1, Tp, Tp), lambda b, j, tm: (b, 0, 0))],
         scratch_shapes=[
             pltpu.VMEM((Tp, 1), jnp.float32),
             pltpu.VMEM((Tp, 1), jnp.float32),
@@ -351,17 +369,18 @@ def unembed_verify_stats(hidden, w, candidates, tmax, *, block_v=None,
         ],
     )
     out_shapes = [
-        jax.ShapeDtypeStruct((B, Tp), jnp.int32),
-        jax.ShapeDtypeStruct((B, Tp), jnp.float32),
-        jax.ShapeDtypeStruct((B, Tp), jnp.float32),
+        jax.ShapeDtypeStruct((B, Tp, 1), jnp.int32),
+        jax.ShapeDtypeStruct((B, Tp, 1), jnp.float32),
+        jax.ShapeDtypeStruct((B, Tp, 1), jnp.float32),
         jax.ShapeDtypeStruct((B, Tp, Tp), jnp.float32),
     ]
     argm, m, l, cl = pl.pallas_call(
         functools.partial(_verify_stats_kernel, block_v=block_v, n_v=n_v,
-                          V=V, T=Tp),
+                          V=V, T=Tp, vocab_major=vocab_major),
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
-    )(tmax.astype(jnp.float32), candidates.astype(jnp.int32),
+    )(tmax.astype(jnp.float32),
+      candidates.astype(jnp.int32).reshape(B, 1, Tp),
       hidden, w.astype(hidden.dtype))
-    return argm[:, :T], m[:, :T], l[:, :T], cl[:, :T, :T]
+    return argm[:, :T, 0], m[:, :T, 0], l[:, :T, 0], cl[:, :T, :T]

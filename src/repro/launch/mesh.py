@@ -10,17 +10,9 @@ import jax
 
 
 def mesh_axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types`` kwarg for ``jax.make_mesh`` — empty on jax builds that
-    predate it.
-
-    jax 0.4.3x ships neither ``jax.sharding.AxisType`` nor the
-    ``axis_types`` parameter; newer jax wants the axes declared explicitly
-    as ``Auto``.  Call sites splat the result unconditionally so one code
-    path covers both (the version-compat shim behind the 3 former tier-1
-    collectives/sharding failures)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+    """``axis_types`` kwarg for ``jax.make_mesh``: every axis ``Auto`` —
+    the sharding-in-types default the repo's logical-axis rules assume."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,5 +1,10 @@
-"""Serving launcher: continuous-batching speculative server on a reduced
-model with a pluggable proposer (DESIGN.md §13).
+"""Serving launcher: continuous-batching speculative server with a
+pluggable proposer (DESIGN.md §13).
+
+By default it serves the reduced (CPU-sized) variant of ``--arch``; with
+``--published-widths`` it serves the published config in bf16, cut only in
+depth by ``--layers``.  Engines run the Pallas kernels: compiled on a TPU,
+interpreted on the CPU.
 
   PYTHONPATH=src python -m repro.launch.serve --arch openpangu-7b \
       --requests 16 --slots 4 --max-new 24 --proposer ngram
@@ -7,65 +12,114 @@ model with a pluggable proposer (DESIGN.md §13).
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import sys
 import time
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
 
 from repro.configs.base import SamplingParams, SchedulerParams
 from repro.configs.registry import ALL_ARCHS, get_config
 from repro.core import medusa as M
 from repro.core.engine import build_engine
 from repro.distributed.sharding import split_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model
 from repro.models.frontends import frontend_embeds
 from repro.serving.scheduler import FamilySpecServer, SpecServer
 
 
-def proposer_params(kind: str, cfg, model, eng):
-    """Proposer-side weights for ``kind``: Medusa heads, draft-model
-    weights, or nothing (the train-free n-gram lookup)."""
+def serving_config(arch: str, *, published: bool = False, layers: int = 0,
+                   **fields):
+    """The config to serve: ``arch``'s reduced CPU variant, or with
+    ``published`` its published widths; ``layers`` cuts depth only (0 keeps
+    the config's own).  ``fields`` override cache/verify knobs."""
+    cfg = get_config(arch, reduced=not published)
+    if layers:
+        if not 0 < layers <= cfg.num_layers:
+            raise SystemExit(f"--layers {layers}: {cfg.name} has "
+                             f"{cfg.num_layers} layers")
+        fields["num_layers"] = layers
+    return dataclasses.replace(cfg, **fields) if fields else cfg
+
+
+def _backbone_init(cfg):
+    model = get_model(cfg)
+    return lambda key: model.init_params(key, cfg, dtype=cfg.dtype)
+
+
+def weight_shapes(cfg):
+    """(ShapeDtypeStruct tree, logical-axes tree) of the backbone weights,
+    allocated nowhere — what a sharding plan is computed from."""
+    return split_params(jax.eval_shape(_backbone_init(cfg),
+                                       jax.random.PRNGKey(0)))
+
+
+def init_weights(cfg, seed: int = 0, out_shardings=None):
+    """Random backbone weights in ``cfg.dtype`` from one jitted init.
+
+    The layers are drawn straight into their stacked leaves and placed by
+    ``out_shardings`` as they are made (one device for a replica, the TP
+    plan for ``--tp``), so a published-width model never passes through a
+    float32 copy, a per-layer copy or the default device."""
+    init = _backbone_init(cfg)
+    return jax.jit(lambda k: split_params(init(k))[0],
+                   out_shardings=out_shardings)(jax.random.PRNGKey(seed))
+
+
+def proposer_params(kind: str, cfg, eng, out_shardings=None):
+    """Proposer-side weights for ``kind`` in ``cfg.dtype``: Medusa heads,
+    draft-model weights, or nothing (the train-free n-gram lookup)."""
     if kind == "medusa":
-        pp, _ = split_params(M.init_medusa(jax.random.PRNGKey(1), cfg,
-                                           eng.tb.K))
+        def init(k):
+            return M.init_medusa(k, cfg, eng.tb.K, dtype=cfg.dtype)
     elif kind == "draft":
-        pp, _ = split_params(model.init_params(jax.random.PRNGKey(1),
-                                               eng.proposer.dc))
+        dc = eng.proposer.dc
+
+        def init(k):
+            return get_model(dc).init_params(k, dc, dtype=dc.dtype)
     else:
-        pp = None
-    return pp
+        return None
+    return jax.jit(lambda k: split_params(init(k))[0],
+                   out_shardings=out_shardings)(jax.random.PRNGKey(1))
 
 
-def serve_tp(args, cfg, model, params, axes, sampling):
-    """--tp path: static-batch generation through the shard_map engine
-    (DESIGN.md §18).  Each batch of ``--slots`` prompts runs one jitted
-    ``generate`` whose heads/ffn/vocab/KV shard over the model axis."""
+def random_prompts(cfg, n: int, lo: int = 4, hi: int = 48, seed: int = 0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size,
+                         size=int(rng.integers(lo, hi))).astype(np.int32)
+            for _ in range(n)]
+
+
+def tp_generate(cfg, prompts, *, tp: int, data: int = 1,
+                proposer: str = "medusa", gamma: int = 4,
+                accept: str = "greedy", sampling=None, slots: int = 4,
+                max_len: int = 512, max_new: int = 24):
+    """Static-batch generation through the shard_map engine (DESIGN.md §18).
+
+    Weights are drawn directly into the TP plan's shardings (heads, ffn and
+    vocab split over the model axis; proposer weights replicated), so no
+    device ever holds the whole model.  Each batch of ``slots`` prompts
+    runs one jitted ``generate``.  Returns ([per-prompt output tokens],
+    seconds spent generating)."""
     import jax.numpy as jnp
 
+    from repro.distributed import profiles
     from repro.distributed.tp import build_tp_engine, make_tp_mesh
-    if args.mesh_shape:
-        try:
-            d, m = (int(x) for x in args.mesh_shape.lower().split("x"))
-        except ValueError:
-            raise SystemExit(f"--mesh-shape wants DATAxMODEL (e.g. '1x4'), "
-                             f"got {args.mesh_shape!r}")
-        if m != args.tp:
-            raise SystemExit(f"--mesh-shape model dim {m} != --tp {args.tp}")
-    else:
-        d, m = 1, args.tp
-    mesh = make_tp_mesh(m, data=d)
-    tpe = build_tp_engine(cfg, mesh, args.proposer, gamma=args.gamma,
-                          accept=args.accept, sampling=sampling)
-    sp = tpe.shard_params(params, axes)
-    pp = proposer_params(args.proposer, cfg, model, tpe)
-    pp = tpe.replicate(pp) if pp is not None else None
-    B = args.slots
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, cfg.vocab_size,
-                            size=int(rng.integers(4, 48))).astype(np.int32)
-               for _ in range(args.requests)]
+    mesh = make_tp_mesh(tp, data=data)
+    tpe = build_tp_engine(cfg, mesh, proposer, gamma=gamma, accept=accept,
+                          sampling=sampling)
+    shapes, axes = weight_shapes(cfg)
+    specs = tpe.param_specs(shapes, axes)
+    sp = init_weights(cfg, out_shardings=profiles.to_named(specs, mesh))
+    pp = proposer_params(proposer, cfg, tpe,
+                         out_shardings=NamedSharding(mesh, P()))
+    B = slots
+    outs = []
     t0 = time.time()
-    toks = 0
     for i in range(0, len(prompts), B):
         batch = prompts[i:i + B]
         S = max(len(p) for p in batch)
@@ -76,20 +130,54 @@ def serve_tp(args, cfg, model, params, axes, sampling):
             plen[j] = len(p)
         for j in range(len(batch), B):      # ragged tail: duplicate row 0
             tok[j], plen[j] = tok[0], plen[0]
-        cache = tpe.init_cache(B, args.max_len)
-        _, n_out, _ = tpe.generate(sp, pp, tpe.replicate(jnp.asarray(tok)),
-                                   tpe.replicate(jnp.asarray(plen)), cache,
-                                   args.max_new)
-        toks += int(np.asarray(n_out)[: len(batch)].sum())
-    dt = time.time() - t0
+        cache = tpe.init_cache(B, max_len)
+        out, n_out, _ = tpe.generate(sp, pp, tpe.replicate(jnp.asarray(tok)),
+                                     tpe.replicate(jnp.asarray(plen)), cache,
+                                     max_new)
+        out, n_out = np.asarray(out), np.asarray(n_out)
+        outs += [out[j, :n_out[j]] for j in range(len(batch))]
+    return outs, time.time() - t0
+
+
+def serve_tp(args, cfg, sampling):
+    """--tp path: ``tp_generate`` over the launcher's random prompts."""
+    if args.mesh_shape:
+        try:
+            d, m = (int(x) for x in args.mesh_shape.lower().split("x"))
+        except ValueError:
+            raise SystemExit(f"--mesh-shape wants DATAxMODEL (e.g. '1x4'), "
+                             f"got {args.mesh_shape!r}")
+        if m != args.tp:
+            raise SystemExit(f"--mesh-shape model dim {m} != --tp {args.tp}")
+    else:
+        d, m = 1, args.tp
+    prompts = random_prompts(cfg, args.requests)
+    outs, dt = tp_generate(cfg, prompts, tp=m, data=d,
+                           proposer=args.proposer, gamma=args.gamma,
+                           accept=args.accept, sampling=sampling,
+                           slots=args.slots, max_len=args.max_len,
+                           max_new=args.max_new)
+    toks = sum(len(o) for o in outs)
     print(f"tp={args.tp} mesh=({d}x{m}) proposer={args.proposer}: "
           f"{len(prompts)} requests, {toks} tokens in {dt:.1f}s "
-          f"({toks / dt:.1f} tok/s across {d * m} devices)")
+          f"({toks / dt:.1f} tok/s across {d * m} {device_label()} devices)")
+    return 0
+
+
+def device_label() -> str:
+    dev = jax.devices()[0]
+    return f"{dev.platform} {dev.device_kind}"
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="openpangu-7b", choices=ALL_ARCHS)
+    ap.add_argument("--published-widths", action="store_true",
+                    help="serve the arch's published widths with bf16 "
+                         "weights instead of its reduced CPU variant")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the model to this many layers (depth only; "
+                         "0 = the config's own depth)")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=24)
@@ -170,16 +258,16 @@ def main():
                     help="explicit DATAxMODEL device mesh for --tp (e.g. "
                          "'2x4'); default '1x<tp>'")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = get_config(args.arch, reduced=True)
+    fields = {}
     if args.cache_dtype or args.cache_layout != "dense" or args.verify_fusion:
-        import dataclasses
-        cfg = dataclasses.replace(cfg, cache_dtype=args.cache_dtype,
-                                  cache_layout=args.cache_layout,
-                                  page_size=args.page_size,
-                                  verify_fusion=args.verify_fusion)
-    model = get_model(cfg)
-    params, axes = split_params(model.init_params(jax.random.PRNGKey(0), cfg))
+        fields = dict(cache_dtype=args.cache_dtype,
+                      cache_layout=args.cache_layout,
+                      page_size=args.page_size,
+                      verify_fusion=args.verify_fusion)
+    cfg = serving_config(args.arch, published=args.published_widths,
+                         layers=args.layers, **fields)
     sampling = SamplingParams(temperature=args.temperature, top_p=args.top_p)
     sched = SchedulerParams(chunk_size=args.chunk_size,
                             preemption=args.preemption,
@@ -190,24 +278,34 @@ def main():
             raise SystemExit("--tp serves static batches through the sharded "
                              "engine; it does not combine with --families "
                              "or --replicas")
-        return serve_tp(args, cfg, model, params, axes, sampling)
-
-    def make_server(kind):
-        eng = build_engine(cfg, kind, gamma=args.gamma, accept=args.accept,
-                           sampling=sampling)
-        pp = proposer_params(kind, cfg, model, eng)
-        return SpecServer(eng, params, pp, batch_slots=args.slots,
-                          max_len=args.max_len, admission=args.admission,
-                          prefix_cache=args.prefix_cache, sched=sched)
-
+        return serve_tp(args, cfg, sampling)
     if args.replicas and kinds:
         raise SystemExit("--replicas routes across single-proposer replicas; "
                          "it does not combine with --families")
+
+    weights = {}     # device -> backbone weights placed there
+
+    def make_server(kind, device=None):
+        # proposer weights before the backbone's: a Medusa init holds one
+        # head of scratch, which must not sit on top of a full backbone
+        eng = build_engine(cfg, kind, gamma=args.gamma, accept=args.accept,
+                           sampling=sampling, use_kernel=True)
+        place = None if device is None else SingleDeviceSharding(device)
+        pp = proposer_params(kind, cfg, eng, out_shardings=place)
+        if device not in weights:
+            weights[device] = init_weights(cfg, out_shardings=place)
+        return SpecServer(eng, weights[device], pp, batch_slots=args.slots,
+                          max_len=args.max_len, admission=args.admission,
+                          prefix_cache=args.prefix_cache, sched=sched,
+                          device=device)
+
     if args.replicas:
-        # prefix-affinity front door over N independent replicas (§18)
+        # prefix-affinity front door over N independent replicas (§18), one
+        # device each (round-robin when there are fewer devices)
         from repro.serving.router import ReplicaRouter
+        devs = jax.devices()
         srv = ReplicaRouter(
-            {f"r{i}": make_server(args.proposer)
+            {f"r{i}": make_server(args.proposer, devs[i % len(devs)])
              for i in range(args.replicas)},
             page_size=args.page_size)
     elif kinds:
@@ -241,8 +339,18 @@ def main():
     dt = time.time() - t0
     done = [srv.result(r) for r in rids]
     toks = sum(len(r.output) for r in done if r.status == "done")
+    failed = [r.rid for r in done if r.status != "done"]
     print(f"{len(done)} requests, {toks} tokens in {dt:.1f}s "
-          f"({iters} scheduler iterations, {toks/dt:.1f} tok/s on CPU)")
+          f"({iters} scheduler iterations, {toks/dt:.1f} tok/s on "
+          f"{device_label()})")
+    if failed:
+        print(f"FAILED: {len(failed)} request(s) did not finish: {failed}",
+              file=sys.stderr)
+    _report(args, srv, kinds, done)
+    return 1 if failed else 0
+
+
+def _report(args, srv, kinds, done):
     if args.replicas:
         snap = srv.snapshot()
         total = snap["affinity_hits"] + snap["affinity_misses"]
@@ -259,7 +367,8 @@ def main():
         return
     print(f"proposer={args.proposer} admission={args.admission}: "
           f"{srv.stats['admitted']} slot admissions (incl. retries) in "
-          f"{srv.stats['prefill_calls']} prefill calls")
+          f"{srv.stats['prefill_calls']} prefill calls, "
+          f"{srv.stats['step_failures']} recovered step failures")
     if args.cache_layout == "paged":
         print(f"paged: peak {srv.stats['peak_blocks']}/{srv.n_blocks - 1} "
               f"blocks, {srv.stats['deferred']} deferred admissions, "
@@ -279,4 +388,4 @@ def main():
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

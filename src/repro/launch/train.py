@@ -17,6 +17,7 @@ import numpy as np
 from repro.configs.registry import ALL_ARCHS, get_config
 from repro.core import medusa as M
 from repro.distributed.sharding import split_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_model
 from repro.training import checkpoint as C
 from repro.training import data as D
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     model = get_model(cfg)

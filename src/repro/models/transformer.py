@@ -87,6 +87,18 @@ def tree_stack(trees):
     return jax.tree.map(stack, *trees, is_leaf=is_param)
 
 
+def init_units(keys, cfg: ModelConfig):
+    """``tree_stack([init_unit(k, cfg) for k in keys])`` without the stack:
+    the units are drawn under ``vmap`` straight into their stacked leaves
+    (same values — per-key draws do not depend on batching), so a full-
+    width init never holds the per-unit trees and their stacked copy at
+    once."""
+    from repro.distributed.sharding import is_param
+    stacked = jax.vmap(lambda k: init_unit(k, cfg))(keys)
+    return jax.tree.map(lambda p: Param(p.value, ("layers",) + p.axes),
+                        stacked, is_leaf=is_param)
+
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -121,7 +133,7 @@ def init_params(key, cfg: ModelConfig, dtype: Optional[str] = None):
     params = {
         "embed": L.dense_init(ks[0], (cfg.vocab_size, cfg.d_model),
                               ("vocab", "embed"), dt, scale=0.02),
-        "units": tree_stack([init_unit(ks[1 + i], cfg) for i in range(nu)]),
+        "units": init_units(ks[1:nu + 1], cfg),
         "final_norm": L.init_norm(ks[nu + 1], cfg),
     }
     if not cfg.tie_embeddings:

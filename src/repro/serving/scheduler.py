@@ -106,6 +106,7 @@ Overload countermeasures (DESIGN.md §14), opted in via ``SchedulerParams``:
 """
 from __future__ import annotations
 
+import logging
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -120,6 +121,8 @@ from repro.core.engine import SpecEngine
 from repro.kernels.paging import blocks_for
 from repro.models.transformer import PAGES_KEY
 from repro.serving.block_pool import BlockPool, PrefixCache
+
+log = logging.getLogger(__name__)
 
 NO_EOS = -1  # device-side "no eos configured" sentinel (token ids are >= 0)
 
@@ -248,6 +251,10 @@ class SpecServer:
     with ``blocks_for_budget``).  ``prefix_cache=True`` enables the §12
     shared-prefix registry (paged layout only, attention-only families,
     proposers that can be primed from a prompt suffix).
+
+    ``device`` pins the server's device state and host-driven steps to one
+    device (a router replica per chip); ``params`` should already live
+    there.  None keeps JAX's default device.
     """
 
     def __init__(self, engine: SpecEngine, params, proposer_params,
@@ -255,9 +262,11 @@ class SpecServer:
                  prompt_buckets=(32, 128, 512), max_retries: int = 1,
                  admission: str = "batched", n_blocks: Optional[int] = None,
                  prefix_cache: bool = False,
-                 sched: Optional[SchedulerParams] = None):
+                 sched: Optional[SchedulerParams] = None,
+                 device=None):
         assert admission in ("batched", "serial"), admission
         self.engine = engine
+        self.device = device
         self.cfg = engine.cfg
         self.model = engine.model
         self.params = params
@@ -339,8 +348,9 @@ class SpecServer:
         self._level = len(self._levels) - 1   # start at full speculation
         self.stats = self._fresh_stats()
 
-        self._reset_device_state()
-        self._key = jax.random.PRNGKey(0)
+        with jax.default_device(self.device):
+            self._reset_device_state()
+            self._key = jax.random.PRNGKey(0)
 
         # host mirrors of the per-slot device bookkeeping inputs
         self._active = np.zeros((self.B,), bool)
@@ -406,7 +416,10 @@ class SpecServer:
                 # §17 rollback counter: slot-steps whose SSM recurrent state
                 # was restored from the speculation-root checkpoint (masked
                 # rows of a step/chunk call; 0 for attention-only families)
-                "ssm_restores": 0}
+                "ssm_restores": 0,
+                # iterations whose admit/decode raised and were recovered
+                # (device faults included — XLA runtime errors, OOM)
+                "step_failures": 0}
 
     # ------------------------------------------------------------------ API
 
@@ -472,16 +485,23 @@ class SpecServer:
         like a failed decode step (requests attach to slots before prefill,
         so ``_recover`` sees them).  So do the chunk advance and the decode
         step — mid-chunk slots re-queue like any in-flight request
-        (DESIGN.md §14)."""
-        try:
-            self._admit()
-            if fail_hook is not None and fail_hook(it):
-                raise RuntimeError("injected step failure")
-            self._chunk_step()
-            self._decode_step()
-        except RuntimeError:
-            self._recover()
-        self._reap()
+        (DESIGN.md §14).  Every recovered failure is logged and counted in
+        ``stats["step_failures"]``: XLA's runtime and out-of-memory errors
+        are RuntimeErrors too, and must not pass for a clean run."""
+        with jax.default_device(self.device):
+            try:
+                self._admit()
+                if fail_hook is not None and fail_hook(it):
+                    raise RuntimeError("injected step failure")
+                self._chunk_step()
+                self._decode_step()
+            except RuntimeError as e:
+                self.stats["step_failures"] += 1
+                log.warning("scheduler iteration %d failed, re-queueing "
+                            "in-flight requests: %s: %s", it,
+                            type(e).__name__, e)
+                self._recover()
+            self._reap()
 
     def run(self, max_iters: int = 10_000,
             fail_hook: Optional[Callable[[int], bool]] = None):
@@ -524,7 +544,8 @@ class SpecServer:
             slot.request = None
         self.stats = self._fresh_stats()
         self._level = len(self._levels) - 1
-        self._reset_device_state()
+        with jax.default_device(self.device):
+            self._reset_device_state()
         self._reset_host_slots()
 
     def _reset_host_slots(self):

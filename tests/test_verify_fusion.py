@@ -82,6 +82,25 @@ def test_verify_stats_kernel_multi_block_close(rng):
     np.testing.assert_array_equal(np.asarray(cand_w), np.asarray(out[3]))
 
 
+def test_verify_stats_kernel_ragged_vocab_major(rng):
+    """V no multiple of the block (or of 128) with a 128-aligned d: the
+    kernel sweeps the head's [V, d] transpose in cdiv(V, block_v) blocks
+    and masks the ragged tail — same statistics as the oracle (exact
+    argmax, f32-rounding-close values)."""
+    B, T, d, Vc = 2, 4, 128, 300
+    hidden = jnp.asarray(rng.standard_normal((B, T, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((d, Vc)), jnp.float32) * 0.3
+    cand = jnp.asarray(rng.integers(0, Vc, (B, T)), jnp.int32)
+    tmax = jnp.asarray([1.0, 0.7], jnp.float32)
+    argm, m, l, cand_w = KR.verify_stats_ref(hidden, w, cand, tmax)
+    out = KO.verify_stats(hidden, w, cand, tmax, block_v=128, interpret=True)
+    np.testing.assert_array_equal(np.asarray(argm), np.asarray(out[0]))
+    np.testing.assert_allclose(np.asarray(m), np.asarray(out[1]), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(l), np.asarray(out[2]), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(cand_w), np.asarray(out[3]),
+                               rtol=1e-5, atol=1e-6)
+
+
 # ----------------------------------------------- walk differential (no E2E)
 
 def _stats_and_logits(rng, B, T, Vc, temp):
