@@ -56,6 +56,7 @@ def commit_rows(cache, rows, lengths, *, interpret: bool | None = None):
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
         input_output_aliases={2: 0},   # cache arg -> output (in-place)
         interpret=interpret,
+        name="commit_rows",
     )
     return fn(lengths, rows.astype(cache.dtype), cache)
 
@@ -118,6 +119,7 @@ def commit_rows_paged(pool, block_tables, rows, lengths, *,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={3: 0},   # pool arg -> output (in-place)
         interpret=interpret,
+        name="commit_rows_paged",
     )
     return fn(lengths, block_tables.astype(jnp.int32),
               rows.astype(pool.dtype), pool)
@@ -294,6 +296,7 @@ def fused_qkv_rope_commit(x, p, lengths, k_cache, v_cache, *, cos=None,
         out_shape=out_shapes,
         input_output_aliases={kc_idx: 3, kc_idx + 1: 4},
         interpret=interpret,
+        name="fused_qkv_rope_commit",
     )
     if paged:
         return fn(lengths, table.astype(jnp.int32), *inputs)

@@ -222,6 +222,7 @@ def flash_decode(q, k, v, lengths, *, k_scale=None, v_scale=None,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
+        name="flash_decode",
     )
     if paged:
         return fn(lengths, block_tables.astype(jnp.int32), *inputs)
@@ -380,6 +381,7 @@ def unembed_verify_stats(hidden, w, candidates, tmax, *, block_v=None,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
+        name="unembed_verify_stats",
     )(tmax.astype(jnp.float32),
       candidates.astype(jnp.int32).reshape(B, 1, Tp),
       hidden, w.astype(hidden.dtype))

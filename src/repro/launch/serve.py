@@ -12,6 +12,7 @@ interpreted on the CPU.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import sys
 import time
@@ -257,6 +258,10 @@ def main():
     ap.add_argument("--mesh-shape", default="",
                     help="explicit DATAxMODEL device mesh for --tp (e.g. "
                          "'2x4'); default '1x<tp>'")
+    ap.add_argument("--profile", default="", metavar="DIR",
+                    help="write a profiler trace of the serving loop to DIR: "
+                         "the scheduler's serve.* spans and the named "
+                         "programs on the device's clock (README, Tracing)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -274,6 +279,9 @@ def main():
                             adaptive_gamma=args.adaptive_gamma)
     kinds = [k.strip() for k in args.families.split(",") if k.strip()]
     if args.tp:
+        if args.profile:
+            raise SystemExit("--profile traces the scheduler's serving loop; "
+                             "--tp serves static batches without it")
         if kinds or args.replicas:
             raise SystemExit("--tp serves static batches through the sharded "
                              "engine; it does not combine with --families "
@@ -335,7 +343,9 @@ def main():
         if kinds:
             kw["group"] = kinds[r % len(kinds)]   # round-robin across lanes
         rids.append(srv.submit(prompt, **kw))
-    iters = srv.run()
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        iters = srv.run()
     dt = time.time() - t0
     done = [srv.result(r) for r in rids]
     toks = sum(len(r.output) for r in done if r.status == "done")
@@ -369,6 +379,11 @@ def _report(args, srv, kinds, done):
           f"{srv.stats['admitted']} slot admissions (incl. retries) in "
           f"{srv.stats['prefill_calls']} prefill calls, "
           f"{srv.stats['step_failures']} recovered step failures")
+    slot_steps = srv.stats["slot_steps"]
+    print(f"acceptance: {srv.stats['accepted_tokens']} tokens accepted over "
+          f"{slot_steps} slot-steps "
+          f"({srv.stats['accepted_tokens'] / max(slot_steps, 1):.3f} per "
+          f"slot-step, before EOS/budget clipping)")
     if args.cache_layout == "paged":
         print(f"paged: peak {srv.stats['peak_blocks']}/{srv.n_blocks - 1} "
               f"blocks, {srv.stats['deferred']} deferred admissions, "

@@ -106,6 +106,7 @@ Overload countermeasures (DESIGN.md §14), opted in via ``SchedulerParams``:
 """
 from __future__ import annotations
 
+import functools
 import logging
 import time
 from collections import deque
@@ -115,6 +116,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import SchedulerParams
 from repro.core.engine import SpecEngine
@@ -189,6 +191,11 @@ class Request:
     # preemption recovery can re-run the encoder pass (DESIGN.md §17)
     frames: Optional[np.ndarray] = None
     submitted_at: float = field(default_factory=time.monotonic)
+    # start of the admission round that first bound it to a slot, and the
+    # time its first token was appended (``time.monotonic``, the clock of
+    # ``submitted_at``); a resume or a retry moves neither
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
     output: List[int] = field(default_factory=list)
     steps: int = 0
     retries: int = 0
@@ -219,6 +226,16 @@ class SlotSync(NamedTuple):
                             # EOS/budget clip): feeds the host's committed-
                             # length mirror and the adaptive-speculation
                             # acceptance EMA (DESIGN.md §14)
+
+
+def named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: ``jax.jit(named(fn, name))`` compiles the
+    program ``jit_<name>``, the name the device trace's ``XLA Modules`` line
+    and the host's ``PjitFunction(<name>)`` events carry (a lambda or a
+    bound method would show as ``jit__lambda`` or its method name)."""
+    program = functools.partial(fn)
+    program.__name__ = name
+    return program
 
 
 def _pow2(n: int) -> int:
@@ -371,38 +388,43 @@ class SpecServer:
         # [n_group, bucket] admission variants share a single cache here.
         # The B-slot cache/state args are donated: the old buffers are dead
         # after each call, so XLA aliases them instead of holding 2x cache.
+        # Each program carries a stable name (``named``) for the trace.
         self._admit_jit = jax.jit(
-            self._admit_paged_impl if self.paged else self._admit_bucket_impl,
+            named(self._admit_paged_impl if self.paged
+                  else self._admit_bucket_impl, "admit_group"),
             donate_argnums=(7, 8, 9, 10, 11))  # speclint: donates=cache,lengths,base,pstate,n_out
-        self._prefill_jit = jax.jit(
+        self._prefill_jit = jax.jit(named(
             lambda p, pp, t, l, c, key, temp, topp, st, fr=None:
                 self.engine.prefill(
                     p, pp, t, l, c, extra_embeds=fr, key=key,
-                    temperature=temp, top_p=topp, state=st))
-        self._step_jit = jax.jit(self._serve_step_impl,
+                    temperature=temp, top_p=topp, state=st), "prefill_one"))
+        self._step_jit = jax.jit(named(self._serve_step_impl, "serve_step"),
                                  donate_argnums=(2, 3, 4, 5, 6))  # speclint: donates=cache,lengths,base,pstate,n_out
         # per-level step graphs (the full-tree level deliberately does NOT
         # alias self._step_jit: tests monkeypatch _step_jit to inject
         # failures, and that must keep working for the default path)
         self._step_jits = [
-            jax.jit((lambda _dt: lambda *a: self._serve_step_impl(
-                *a, dtree=_dt))(dt),
-                donate_argnums=(2, 3, 4, 5, 6))  # speclint: donates=cache,lengths,base,pstate,n_out
+            jax.jit(named(functools.partial(self._serve_step_impl, dtree=dt),
+                          "serve_step"),
+                    donate_argnums=(2, 3, 4, 5, 6))  # speclint: donates=cache,lengths,base,pstate,n_out
             for _, dt in self._levels]
-        self._trim_jit = jax.jit(
+        self._trim_jit = jax.jit(named(
             lambda st, keep: self.engine.proposer.reset_rows(st, keep),
+            "trim_proposer"),
             donate_argnums=(0,))  # speclint: donates=st
         if self.paged or self.chunk:
-            self._suffix_jit = jax.jit(self._suffix_impl,
+            self._suffix_jit = jax.jit(named(self._suffix_impl,
+                                             "suffix_prefill"),
                                        donate_argnums=(6, 7, 8, 9, 10))  # speclint: donates=cache,lengths,base,pstate,n_out
         if self.paged:
-            self._copy_jit = jax.jit(self._copy_blocks_impl,
+            self._copy_jit = jax.jit(named(self._copy_blocks_impl,
+                                           "copy_blocks"),
                                      donate_argnums=(0,))  # speclint: donates=cache
         if getattr(self.engine.proposer, "primes_from_tokens", False):
-            self._prime_tokens_jit = jax.jit(
+            self._prime_tokens_jit = jax.jit(named(
                 lambda st, toks, tl, base, mask:
                     self.engine.proposer.prime_tokens(st, toks, tl, base,
-                                                      mask),
+                                                      mask), "prime_tokens"),
                 donate_argnums=(0,))  # speclint: donates=st
 
     def _fresh_stats(self) -> dict:
@@ -419,7 +441,13 @@ class SpecServer:
                 "ssm_restores": 0,
                 # iterations whose admit/decode raised and were recovered
                 # (device faults included — XLA runtime errors, OOM)
-                "step_failures": 0}
+                "step_failures": 0,
+                # active slots summed over decode steps (counted at
+                # dispatch), and the verifier's raw acceptance
+                # (SlotSync.spec_acc, before EOS/budget clipping) summed
+                # over them: accepted_tokens / slot_steps is the accepted
+                # length per slot-step
+                "slot_steps": 0, "accepted_tokens": 0}
 
     # ------------------------------------------------------------------ API
 
@@ -487,20 +515,29 @@ class SpecServer:
         step — mid-chunk slots re-queue like any in-flight request
         (DESIGN.md §14).  Every recovered failure is logged and counted in
         ``stats["step_failures"]``: XLA's runtime and out-of-memory errors
-        are RuntimeErrors too, and must not pass for a clean run."""
-        with jax.default_device(self.device):
+        are RuntimeErrors too, and must not pass for a clean run.
+
+        Under the profiler the iteration is a ``serve.step`` span holding
+        ``serve.admit``, ``serve.chunk``, ``serve.decode`` (dispatch),
+        ``serve.sync`` (the host blocked on the device), ``serve.apply``,
+        ``serve.reap`` and ``serve.recover``; off, each costs about a
+        microsecond and formats nothing."""
+        with jax.default_device(self.device), TraceAnnotation("serve.step"):
             try:
                 self._admit()
                 if fail_hook is not None and fail_hook(it):
                     raise RuntimeError("injected step failure")
-                self._chunk_step()
+                if self._chunk_state:
+                    with TraceAnnotation("serve.chunk"):
+                        self._chunk_step()
                 self._decode_step()
             except RuntimeError as e:
                 self.stats["step_failures"] += 1
                 log.warning("scheduler iteration %d failed, re-queueing "
                             "in-flight requests: %s: %s", it,
                             type(e).__name__, e)
-                self._recover()
+                with TraceAnnotation("serve.recover"):
+                    self._recover()
             self._reap()
 
     def run(self, max_iters: int = 10_000,
@@ -762,6 +799,14 @@ class SpecServer:
         return req.prompt, req.max_new
 
     def _admit(self):
+        """One admission round (``_admit_round``) under a ``serve.admit``
+        span that carries the requests admitted and the first rid."""
+        with TraceAnnotation("serve.admit") as span:
+            took = self._admit_round(time.monotonic())
+            if took:
+                span.set_metadata(admitted=len(took), rid=took[0].rid)
+
+    def _admit_round(self, now: float) -> List[Request]:
         """Admission round (host): drain the queue into free slots.
 
         Dense: the free-slot count is the only resource.  Paged (DESIGN.md
@@ -775,7 +820,10 @@ class SpecServer:
         longer than ``sched.chunk_size`` only install their slot here and
         stream through ``_chunk_step``; the rest go through the bucketed
         group prefill, whose writes land directly in the global pool
-        through the group's table rows."""
+        through the group's table rows.
+
+        ``now`` stamps ``admitted_at`` on first binding; returns the requests
+        bound to slots this round."""
         free = [i for i, s in enumerate(self.slots) if s.free]
         take: List[tuple] = []
         while self.queue and len(take) < len(free):
@@ -807,11 +855,13 @@ class SpecServer:
                 break
             take.append((req, plan, p_ext, mn))
         if not take:
-            return
+            return []
         pairs = []          # (slot, req, p_ext) for this round's prefills
         cows = []
         for i, (req, plan, p_ext, mn) in zip(free, take):
             req.status = "running"
+            if req.admitted_at is None:
+                req.admitted_at = now
             self.slots[i].request = req
             if req.output:
                 self.stats["resumed"] += 1
@@ -851,6 +901,7 @@ class SpecServer:
                 self._prefill_one(req, i, p_ext)
         else:
             self._admit_batched(pairs)
+        return [req for req, *_ in take]
 
     # ---- paged admission (host side, DESIGN.md §12) -----------------------
 
@@ -1103,9 +1154,8 @@ class SpecServer:
         chunk lands here becomes active (its base token and primed
         proposer state come from that last call, exactly like a prefix-
         cache suffix admission) and, under the paged layout, registers its
-        prompt in the prefix registry."""
-        if not self._chunk_state:
-            return
+        prompt in the prefix registry.  Runs only while a slot is
+        mid-chunk (``step_once`` checks)."""
         self._push_table()
         C = self.chunk
         stoks = np.zeros((self.B, C), np.int32)
@@ -1257,11 +1307,30 @@ class SpecServer:
         (inactive, request attached) are skipped by the masked commit and
         by the host apply.  Under §14 the step may run a smaller graph
         from the adaptive family, and ``_ensure_blocks`` grows optimistic
-        allocations (possibly preempting) before any write happens."""
+        allocations (possibly preempting) before any write happens.
+
+        Spans: ``serve.decode`` up to the step call's return (dispatch),
+        ``serve.sync`` the one ``device_get``, ``serve.apply`` the rest."""
+        with TraceAnnotation("serve.decode"):
+            launched = self._launch_step()
+        if launched is None:
+            return
+        sync, gamma = launched
+        with TraceAnnotation("serve.sync"):
+            # one transfer for the whole SlotSync (speclint trace-safety:
+            # the old per-field np.asarray calls cost four device
+            # round-trips per decode step)
+            sync = jax.device_get(sync)
+        with TraceAnnotation("serve.apply"):
+            self._apply(sync, gamma)
+
+    def _launch_step(self):
+        """Dispatch the decode step; (device SlotSync, gamma), or None when
+        no slot is active."""
         if self.paged and self.preemption:
             self._ensure_blocks()
         if not self._active.any():
-            return
+            return None
         self._push_table()
         self._key, sub = jax.random.split(self._key)
         if self._slotmeta_dev is None:
@@ -1282,34 +1351,50 @@ class SpecServer:
             maxnew, temp, topp)
         self.stats["steps"] += 1
         self.stats["gamma_steps"][gamma] += 1
+        self.stats["slot_steps"] += int(self._active.sum())
         if self.cfg.num_ssm_layers:
             # masked slots (empty / mid-chunk) restored their SSM state
             # from the speculation-root checkpoint this step (§17)
             self.stats["ssm_restores"] += int((~self._active).sum())
-        # one transfer for the whole SlotSync (speclint trace-safety: the
-        # old per-field np.asarray calls cost four device round-trips per
-        # decode step)
-        sync = jax.device_get(sync)
+        return sync, gamma
+
+    def _apply(self, sync: SlotSync, gamma: int):
+        """Apply a fetched ``SlotSync`` on the host: the committed-length
+        mirror, the acceptance EMA and counter, and each active slot's new
+        tokens (stamping ``first_token_at`` on a request's first)."""
         acc, toks, spec_acc = sync.acc, sync.tokens, sync.spec_acc
         self._done_now = np.array(sync.done)   # copy: host-mutated at reap
         # committed-length mirror + acceptance EMA (§14): spec_acc is the
-        # raw verifier acceptance = exactly what commit advanced by
+        # raw verifier acceptance = exactly what commit advanced by (0 on
+        # inactive slots)
         self._len_host[self._active] += spec_acc[self._active]
+        self.stats["accepted_tokens"] += int(spec_acc.sum())
         d = self.sched.accept_ema
         ratio = (spec_acc - 1.0) / max(gamma, 1)
         self._acc_ema[self._active] = (
             d * self._acc_ema[self._active]
             + (1.0 - d) * ratio[self._active])
+        now = time.monotonic()
         for i, slot in enumerate(self.slots):
             req = slot.request
             if req is None or not self._active[i]:
                 continue
             req.steps += 1
+            if req.first_token_at is None and acc[i]:
+                req.first_token_at = now
             req.output.extend(int(t) for t in toks[i, : acc[i]])
 
     def _reap(self):
         """Batch-reap every slot the device marked done plus host-side
-        stragglers; freed slots refill together on the next ``_admit``."""
+        stragglers; freed slots refill together on the next ``_admit``.
+        A ``serve.reap`` span carries the slots freed."""
+        with TraceAnnotation("serve.reap") as span:
+            freed = self._reap_slots()
+            if freed:
+                span.set_metadata(freed=len(freed))
+
+    def _reap_slots(self) -> List[int]:
+        """The reap itself; returns the freed slots."""
         now = time.monotonic()
         freed = []
         for i, slot in enumerate(self.slots):
@@ -1352,6 +1437,7 @@ class SpecServer:
                 self._chunk_state.pop(i, None)
                 self._len_host[i] = 0
                 self._acc_ema[i] = 1.0
+        return freed
 
     def _recover(self):
         """Node-failure recovery: re-queue all in-flight work (their caches

@@ -169,3 +169,30 @@ def test_speclint_cli_standalone():
     assert r.returncode == 0
     for rule, *_ in CASES:
         assert rule in r.stdout
+
+
+def test_named_jit_targets_are_seen_through(tmp_path):
+    """``jax.jit(named(f, name))`` resolves to ``f``, as ``partial`` does:
+    its donation pin is checked against ``f``'s signature and ``f`` is
+    jit-reachable for the trace-safety rule."""
+    src = tmp_path / "named_target.py"
+    src.write_text(
+        "import jax\n"
+        "import numpy as np\n"
+        "\n"
+        "\n"
+        "def named(fn, name):\n"
+        "    return fn\n"
+        "\n"
+        "\n"
+        "class S:\n"
+        "    def _impl(self, params, cache, lengths):\n"
+        "        return cache, np.asarray(lengths)\n"
+        "\n"
+        "    def build(self):\n"
+        "        return jax.jit(named(self._impl, 'step'),\n"
+        "                       donate_argnums=(1,))"
+        "  # speclint: donates=lengths\n")
+    found = run_paths([src], root=tmp_path)
+    assert {f.rule for f in found} == {"donation", "trace-safety"}, \
+        [str(f) for f in found]

@@ -8,8 +8,9 @@ closures, dispatch tables), so this is a deliberate over-approximation:
   ``pl.pallas_call`` / ``lax.while_loop|fori_loop|scan|cond|switch`` /
   ``jax.vmap`` (at the callee's function-argument positions only), plus
   defs decorated with ``jit``/``remat``-family decorators.  Lambda seeds
-  contribute their bodies directly.  ``functools.partial`` and the
-  ``a if c else b`` jit-target idiom the scheduler uses are unwrapped.
+  contribute their bodies directly.  ``functools.partial``, the
+  scheduler's ``named(f, name)`` and the ``a if c else b`` jit-target
+  idiom it uses are unwrapped.
 * **edges** — a call ``anything.f(...)`` reaches every def named ``f``
   anywhere in the scanned set, whatever its receiver.
 
@@ -54,14 +55,16 @@ def root_name(node) -> str | None:
 
 def func_targets(node) -> List[ast.AST]:
     """Function references inside a seed argument: names/attributes,
-    lambdas, both arms of ``a if c else b``, ``partial(f, ...)``."""
+    lambdas, both arms of ``a if c else b``, ``partial(f, ...)`` and
+    ``named(f, name)`` (the scheduler's program-naming wrapper)."""
     out: List[ast.AST] = []
     if isinstance(node, (ast.Name, ast.Attribute, ast.Lambda)):
         out.append(node)
     elif isinstance(node, ast.IfExp):
         out.extend(func_targets(node.body))
         out.extend(func_targets(node.orelse))
-    elif isinstance(node, ast.Call) and last_name(node.func) == "partial":
+    elif isinstance(node, ast.Call) and last_name(node.func) in ("partial",
+                                                                 "named"):
         if node.args:
             out.extend(func_targets(node.args[0]))
     return out
