@@ -361,6 +361,19 @@ class SpecEngine:
                                               row_fn, temperature=temperature)
         return V.greedy_verify_stats(cand, stats, dt)
 
+    def lm_head_passes(self, B: int, dtree=None) -> int:
+        """Passes over the lm head that the fused verify epilogue makes in
+        one step of B slots on ``dtree`` (the engine's tree by default):
+        ``cdiv(B, G)`` for the kernel's slot group G
+        (``kernels.tree_attention.verify_stats_plan``); 0 without verify
+        fusion.  Static per shape, so the host counts it with no sync."""
+        if not self.verify_fusion:
+            return 0
+        from repro.kernels.tree_attention import lm_head_passes
+        dt = self.dtree if dtree is None else dtree
+        return lm_head_passes(B, dt.T, self.cfg.d_model, self.cfg.vocab_size,
+                              self.cfg.dtype)
+
     def step_dtrees(self, levels=()):
         """The adaptive-speculation graph family (DESIGN.md §14): a small,
         static list of ``(gamma, DeviceTree)`` step topologies, ascending,

@@ -233,27 +233,86 @@ def flash_decode(q, k, v, lengths, *, k_scale=None, v_scale=None,
 # fused verify epilogue: unembed + acceptance statistics (DESIGN.md §15)
 # ---------------------------------------------------------------------------
 
-def _verify_stats_kernel(tmax_ref,             # scalar prefetch [B] f32
-                         cand_ref, h_ref, w_ref,  # [1,1,T] i32, [1,T,d],
-                                                  # [d,BV] (or [BV,d])
+# Scoped VMEM the verify kernel asks for, and the budget its slot groups are
+# sized against: a TPU v5e holds 128 MiB of VMEM and scopes 16 MiB to a
+# kernel unless told otherwise.
+VERIFY_VMEM_LIMIT = 48 << 20
+
+
+def _default_block_v(V: int) -> int:
+    return V if V <= 4096 else 512
+
+
+def _verify_vmem_bytes(G: int, Tp: int, d: int, block_v: int,
+                       itemsize: int) -> int:
+    """VMEM of one grid step for a group of G slots: the double-buffered
+    hidden rows and head block, the double-buffered per-row temperatures
+    and outputs, the carry, and six f32 [G*Tp, block_v] temporaries of the
+    body (logits, their bf16 round trip, warped logits, column ids, exp,
+    one spare).  A lane dim narrower than 128 occupies 128."""
+    rows, lane = G * Tp, 128
+    dp = -(-d // lane) * lane
+    cl = rows * (-(-Tp // lane) * lane) * 4
+    return (2 * rows * dp * itemsize                # hidden rows
+            + 2 * block_v * dp * itemsize           # head block
+            + 2 * 4 * rows * lane * 4               # tmax in, argm/m/l out
+            + 2 * cl                                # cand_w out
+            + 3 * rows * lane * 4 + cl              # carry
+            + 6 * rows * block_v * 4                # body temporaries
+            + block_v * lane * 4)                   # one-hot
+
+
+def verify_stats_plan(B: int, Tp: int, d: int, block_v: int, dtype, *,
+                      max_group: int | None = None):
+    """(G, block_v, vmem_bytes) for ``unembed_verify_stats``: the largest
+    group of G <= B slots whose grid step fits ``VERIFY_VMEM_LIMIT``.
+
+    Each group streams the whole lm head once, so a call makes
+    ``cdiv(B, G)`` head passes; G is then evened out to the smallest group
+    that makes as few passes (B = 32 that fits 18 runs two groups of 16).
+    G = 1 is one pass per slot; ``max_group`` caps G (tests pin it to
+    compare groupings).  When not even one slot fits, G is 1 and the
+    compiler has the last word."""
+    block_v = max(-(-block_v // 128) * 128, 128)
+    itemsize = jnp.dtype(dtype).itemsize
+    cap = B if max_group is None else max(1, min(B, max_group))
+    G = next((g for g in range(cap, 1, -1) if _verify_vmem_bytes(
+        g, Tp, d, block_v, itemsize) <= VERIFY_VMEM_LIMIT), 1)
+    G = -(-B // -(-B // G))        # same passes, groups as even as they go
+    return G, block_v, _verify_vmem_bytes(G, Tp, d, block_v, itemsize)
+
+
+def lm_head_passes(B: int, T: int, d: int, V: int, dtype) -> int:
+    """Passes over the lm head one ``unembed_verify_stats`` call makes for
+    [B, T, d] hidden rows of ``dtype`` and a V-column head, as planned."""
+    Tp = T + (-T % 8)
+    G, _, _ = verify_stats_plan(B, Tp, d, _default_block_v(V), dtype)
+    return -(-B // G)
+
+
+def _verify_stats_kernel(cand_ref, h_ref, t_ref, w_ref,
                          argm_ref, m_ref, l_ref, cl_ref,
                          wmax_scr, lsum_scr, amax_scr, cl_scr,
-                         *, block_v: int, n_v: int, V: int, T: int,
+                         *, block_v: int, n_v: int, V: int, T: int, G: int,
                          vocab_major: bool):
-    """One (b, j) grid step of the vocab sweep.
+    """One (g, j) grid step of the vocab sweep for a group of G slots.
 
-    Streams the lm-head matmul over vocab blocks and keeps only the
-    Verdict-sized acceptance statistics in VMEM: per-node argmax (first-wins
-    across blocks via a strict-greater merge), warped-logit max ``m`` and
-    sum-exp ``l`` (online softmax carry), and the [T, T] candidate-logit
-    table extracted by a one-hot matmul — exact, because each output element
-    is one ``x * 1`` plus exact zeros.  The full [T, BV] logits block dies
-    in VMEM; nothing [*, V]-shaped reaches HBM.  The last vocab block may
-    run past V: its out-of-range columns hold unspecified values and are
-    masked to NEG_INF before any statistic reads them.  ``vocab_major``
-    blocks arrive as [BV, d] rows of the transposed head.
+    Blocks: cand [G, 1, T] i32; h [G*T, d], the group's tree rows, resident
+    across the sweep; t [G*T, 1] f32, each row's warp temperature; w
+    [d, BV] (or [BV, d] rows of the transposed head when ``vocab_major``).
+
+    Streams the lm-head matmul over vocab blocks, one [G*T, d] x [d, BV]
+    product per step, so each block of the head leaves HBM once per group.
+    Only Verdict-sized acceptance statistics stay in VMEM, per row:
+    argmax (first-wins across blocks via a strict-greater merge), warped
+    max ``m`` and sum-exp ``l`` (online softmax carry), and each slot's
+    [T, T] candidate-logit table extracted by a one-hot matmul — exact,
+    because each output element is one ``x * 1`` plus exact zeros.  The
+    [G*T, BV] logits block dies in VMEM; nothing [*, V]-shaped reaches
+    HBM.  The last vocab block may run past V: its out-of-range columns
+    hold unspecified values and are masked to NEG_INF before any
+    statistic reads them.
     """
-    b = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -263,20 +322,20 @@ def _verify_stats_kernel(tmax_ref,             # scalar prefetch [B] f32
         amax_scr[...] = jnp.zeros_like(amax_scr)
         cl_scr[...] = jnp.zeros_like(cl_scr)
 
-    h = h_ref[0]                                   # [T, d]
+    h = h_ref[...]                                 # [G*T, d]
     z = jax.lax.dot_general(
         h, w_ref[...], (((1,), (1 if vocab_major else 0,)), ((), ())),
-        preferred_element_type=jnp.float32)        # [T, BV]
+        preferred_element_type=jnp.float32)        # [G*T, BV]
     # round through the activation dtype (bf16 configs) so the stats match
     # the unfused ``unembed`` einsum, then warp exactly as
     # ``sampling.warp_logits``: true division by the clamped temperature
     # (monotonic, so argmax is shared with raw logits)
     z = z.astype(h.dtype).astype(jnp.float32)
-    wv = z / tmax_ref[b]
+    wv = z / t_ref[...]
     col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, wv.shape, 1)
     wv = jnp.where(col < V, wv, NEG_INF)           # pad columns: exact no-ops
 
-    bm = jnp.max(wv, axis=1, keepdims=True)        # [T, 1]
+    bm = jnp.max(wv, axis=1, keepdims=True)        # [G*T, 1]
     bi = jnp.argmax(wv, axis=1)[:, None].astype(jnp.int32) + j * block_v
     m_prev = wmax_scr[...]
     amax_scr[...] = jnp.where(bm > m_prev, bi, amax_scr[...])
@@ -286,23 +345,28 @@ def _verify_stats_kernel(tmax_ref,             # scalar prefetch [B] f32
         jnp.exp(wv - m_new), axis=1, keepdims=True)
     wmax_scr[...] = m_new
 
-    rel = cand_ref[0] - j * block_v                # [1, T]
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (block_v, T), 0)
-              == rel).astype(jnp.float32)          # [BV, T]
-    cl_scr[...] += jax.lax.dot_general(
-        wv, onehot, (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)        # [T, T]
+    for g in range(G):                             # each slot's candidates
+        rows = slice(g * T, (g + 1) * T)
+        rel = cand_ref[g] - j * block_v            # [1, T]
+        onehot = (jax.lax.broadcasted_iota(jnp.int32, (block_v, T), 0)
+                  == rel).astype(jnp.float32)      # [BV, T]
+        cl_scr[rows, :] += jax.lax.dot_general(
+            wv[rows], onehot, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)    # [T, T]
 
     @pl.when(j == n_v - 1)
     def _emit():
-        argm_ref[0] = amax_scr[...]
-        m_ref[0] = wmax_scr[...]
-        l_ref[0] = lsum_scr[...]
-        cl_ref[0] = cl_scr[...]
+        for g in range(G):
+            rows = slice(g * T, (g + 1) * T)
+            argm_ref[g] = amax_scr[rows, :]
+            m_ref[g] = wmax_scr[rows, :]
+            l_ref[g] = lsum_scr[rows, :]
+            cl_ref[g] = cl_scr[rows, :]
 
 
 def unembed_verify_stats(hidden, w, candidates, tmax, *, block_v=None,
+                         max_group: int | None = None,
                          interpret: bool = False):
     """Fused unembed + verify statistics (DESIGN.md §15).
 
@@ -315,6 +379,14 @@ def unembed_verify_stats(hidden, w, candidates, tmax, *, block_v=None,
     candidate token ``j`` under node ``t``'s row — everything the greedy
     match and the residual-mass walk need, at O(T^2) instead of O(T*V)
     HBM traffic.
+
+    Slots are folded into the kernel's rows in groups of G
+    (``verify_stats_plan``; ``max_group`` caps G): the grid is
+    ``(cdiv(B, G), n_v)``, and each group sweeps the head once, so a call
+    reads the head ``cdiv(B, G)`` times — once when the group holds every
+    slot.  Every statistic depends only on its own row, so G changes no
+    result.  A B that is no multiple of G pads the hidden rows with zero
+    slots, which are dropped from the outputs.
 
     The vocab sweep runs ``cdiv(V, block_v)`` blocks straight off ``w``: a
     V that is no multiple of ``block_v`` (openpangu's 153,376) leaves a
@@ -335,54 +407,56 @@ def unembed_verify_stats(hidden, w, candidates, tmax, *, block_v=None,
     """
     B, T, d = hidden.shape
     V = w.shape[1]
-    T_pad = -T % 8
-    if T_pad:
-        hidden = jnp.pad(hidden, ((0, 0), (0, T_pad), (0, 0)))
-        candidates = jnp.pad(candidates, ((0, 0), (0, T_pad)))
-    Tp = T + T_pad
-    if block_v is None:
-        block_v = V if V <= 4096 else 512
-    block_v = max(-(-block_v // 128) * 128, 128)
+    Tp = T + (-T % 8)
+    G, block_v, _ = verify_stats_plan(
+        B, Tp, d, _default_block_v(V) if block_v is None else block_v,
+        hidden.dtype, max_group=max_group)
+    nG = pl.cdiv(B, G)
+    Bp = nG * G
+    if Bp > B or Tp > T:
+        hidden = jnp.pad(hidden, ((0, Bp - B), (0, Tp - T), (0, 0)))
+        candidates = jnp.pad(candidates, ((0, Bp - B), (0, Tp - T)))
+        tmax = jnp.pad(tmax, (0, Bp - B), constant_values=1.0)
     n_v = pl.cdiv(V, block_v)
     vocab_major = bool(V % 128) and not d % 128
     if vocab_major:
         w = w.T
-        w_spec = pl.BlockSpec((block_v, d), lambda b, j, tm: (j, 0))
+        w_spec = pl.BlockSpec((block_v, d), lambda g, j: (j, 0))
     else:
-        w_spec = pl.BlockSpec((d, block_v), lambda b, j, tm: (0, j))
+        w_spec = pl.BlockSpec((d, block_v), lambda g, j: (0, j))
 
-    row = pl.BlockSpec((1, Tp, 1), lambda b, j, tm: (b, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, n_v),
-        in_specs=[
-            pl.BlockSpec((1, 1, Tp), lambda b, j, tm: (b, 0, 0)),
-            pl.BlockSpec((1, Tp, d), lambda b, j, tm: (b, 0, 0)),
-            w_spec,
-        ],
-        out_specs=[row, row, row,
-                   pl.BlockSpec((1, Tp, Tp), lambda b, j, tm: (b, 0, 0))],
-        scratch_shapes=[
-            pltpu.VMEM((Tp, 1), jnp.float32),
-            pltpu.VMEM((Tp, 1), jnp.float32),
-            pltpu.VMEM((Tp, 1), jnp.int32),
-            pltpu.VMEM((Tp, Tp), jnp.float32),
-        ],
-    )
-    out_shapes = [
-        jax.ShapeDtypeStruct((B, Tp, 1), jnp.int32),
-        jax.ShapeDtypeStruct((B, Tp, 1), jnp.float32),
-        jax.ShapeDtypeStruct((B, Tp, 1), jnp.float32),
-        jax.ShapeDtypeStruct((B, Tp, Tp), jnp.float32),
-    ]
+    rows = pl.BlockSpec((G * Tp, 1), lambda g, j: (g, 0))
+    per_row = pl.BlockSpec((G, Tp, 1), lambda g, j: (g, 0, 0))
     argm, m, l, cl = pl.pallas_call(
         functools.partial(_verify_stats_kernel, block_v=block_v, n_v=n_v,
-                          V=V, T=Tp, vocab_major=vocab_major),
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
+                          V=V, T=Tp, G=G, vocab_major=vocab_major),
+        grid=(nG, n_v),
+        in_specs=[
+            pl.BlockSpec((G, 1, Tp), lambda g, j: (g, 0, 0)),
+            pl.BlockSpec((G * Tp, d), lambda g, j: (g, 0)),
+            rows,
+            w_spec,
+        ],
+        out_specs=[per_row, per_row, per_row,
+                   pl.BlockSpec((G, Tp, Tp), lambda g, j: (g, 0, 0))],
+        scratch_shapes=[
+            pltpu.VMEM((G * Tp, 1), jnp.float32),
+            pltpu.VMEM((G * Tp, 1), jnp.float32),
+            pltpu.VMEM((G * Tp, 1), jnp.int32),
+            pltpu.VMEM((G * Tp, Tp), jnp.float32),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((Bp, Tp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, Tp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, Tp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, Tp, Tp), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VERIFY_VMEM_LIMIT),
         interpret=interpret,
         name="unembed_verify_stats",
-    )(tmax.astype(jnp.float32),
-      candidates.astype(jnp.int32).reshape(B, 1, Tp),
-      hidden, w.astype(hidden.dtype))
-    return argm[:, :T, 0], m[:, :T, 0], l[:, :T, 0], cl[:, :T, :T]
+    )(candidates.astype(jnp.int32).reshape(Bp, 1, Tp),
+      hidden.reshape(Bp * Tp, d),
+      jnp.repeat(tmax.astype(jnp.float32), Tp)[:, None],
+      w.astype(hidden.dtype))
+    return argm[:B, :T, 0], m[:B, :T, 0], l[:B, :T, 0], cl[:B, :T, :T]

@@ -363,6 +363,8 @@ class SpecServer:
         self._levels = (engine.step_dtrees(self.sched.gamma_levels)
                         if self.adaptive else [(engine.dtree.K, engine.dtree)])
         self._level = len(self._levels) - 1   # start at full speculation
+        self._head_passes = [engine.lm_head_passes(self.B, dt)
+                             for _, dt in self._levels]
         self.stats = self._fresh_stats()
 
         with jax.default_device(self.device):
@@ -447,7 +449,11 @@ class SpecServer:
                 # (SlotSync.spec_acc, before EOS/budget clipping) summed
                 # over them: accepted_tokens / slot_steps is the accepted
                 # length per slot-step
-                "slot_steps": 0, "accepted_tokens": 0}
+                "slot_steps": 0, "accepted_tokens": 0,
+                # passes over the lm head of the fused verify kernel summed
+                # over decode steps (SpecEngine.lm_head_passes; 0 without
+                # verify fusion): one a step when every slot shares a pass
+                "lm_head_passes": 0}
 
     # ------------------------------------------------------------------ API
 
@@ -1352,6 +1358,7 @@ class SpecServer:
         self.stats["steps"] += 1
         self.stats["gamma_steps"][gamma] += 1
         self.stats["slot_steps"] += int(self._active.sum())
+        self.stats["lm_head_passes"] += self._head_passes[self._level]
         if self.cfg.num_ssm_layers:
             # masked slots (empty / mid-chunk) restored their SSM state
             # from the speculation-root checkpoint this step (§17)

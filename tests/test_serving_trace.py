@@ -94,6 +94,23 @@ def test_counters(served, rng):
     assert srv.stats["slot_steps"] == srv.stats["accepted_tokens"] == 0
 
 
+def test_lm_head_passes_one_per_step(served, rng):
+    """Under verify fusion the kernel folds all 8 slots into one group, so
+    the head is read once a step; an unfused server counts none."""
+    cfg, params, _, mp = served
+    counts = {}
+    for vf in (True, False):
+        eng = build_engine(cfg, "medusa", verify_fusion=vf)
+        srv = SpecServer(eng, params, mp, batch_slots=8, max_len=128)
+        for p in _prompts(cfg, rng, (5, 9, 14, 7, 11, 6, 8, 12)):
+            srv.submit(p, max_new=4)
+        srv.run()
+        assert all(r.status == "done" for r in srv.done.values())
+        counts[vf] = (srv.stats["lm_head_passes"], srv.stats["steps"])
+    assert counts[True][0] == counts[True][1] > 0
+    assert counts[False][0] == 0 < counts[False][1]
+
+
 def test_accepted_tokens_equal_delivered_without_clipping(served, rng):
     """With no EOS and budgets far off, nothing is clipped: the verifier's
     raw acceptance is exactly what the requests received."""

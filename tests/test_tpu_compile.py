@@ -17,7 +17,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops as KO
 from repro.kernels.cache_update import (commit_rows, commit_rows_paged,
                                         fused_qkv_rope_commit)
-from repro.kernels.tree_attention import flash_decode, unembed_verify_stats
+from repro.kernels.tree_attention import (flash_decode, unembed_verify_stats,
+                                         verify_stats_plan)
 
 D_MODEL, HQ, HKV, HD, VOCAB = 4096, 32, 8, 128, 153376
 B, T, S, PAGE = 4, 64, 512, 64          # 4 slots, the 64-node Medusa tree
@@ -114,14 +115,18 @@ def test_fused_qkv_rope_commit_compiles(one_chip, layout):
              rope, rope, *extra)
 
 
-def test_unembed_verify_stats_compiles_without_head_copy(one_chip):
-    """B=4, T=64 over the full 153,376-column head: the kernel reads the
-    head where it lies (no pad, no relayout), so scratch stays far below
-    the head's 1.26 GB."""
+@pytest.mark.parametrize("slots", [4, 8])
+def test_unembed_verify_stats_compiles_without_head_copy(one_chip, slots):
+    """4 and 8 slots x T=64 over the full 153,376-column head, every slot
+    in one group (one head pass) within the kernel's VMEM limit: the
+    kernel reads the head where it lies (no pad, no relayout), so scratch
+    stays far below the head's 1.26 GB."""
+    assert verify_stats_plan(slots, T, D_MODEL, 512, BF16)[0] == slots
     compiled = _compile(
         one_chip, lambda h, w, c, t: unembed_verify_stats(
             h, w, c, t, interpret=False),
-        ((B, T, D_MODEL), BF16), ((D_MODEL, VOCAB), BF16),
-        ((B, T), jnp.int32), ((B,), jnp.float32))
+        ((slots, T, D_MODEL), BF16), ((D_MODEL, VOCAB), BF16),
+        ((slots, T), jnp.int32), ((slots,), jnp.float32))
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 64 << 20, f"{temp} bytes of scratch: the lm head is copied"

@@ -38,6 +38,7 @@ from repro.core.tree import cartesian_tree, chain_tree
 from repro.distributed.sharding import split_params
 from repro.kernels import ops as KO
 from repro.kernels import ref as KR
+from repro.kernels import tree_attention as TA
 from repro.models.api import get_model
 
 
@@ -99,6 +100,63 @@ def test_verify_stats_kernel_ragged_vocab_major(rng):
     np.testing.assert_allclose(np.asarray(l), np.asarray(out[2]), rtol=1e-5)
     np.testing.assert_allclose(np.asarray(cand_w), np.asarray(out[3]),
                                rtol=1e-5, atol=1e-6)
+
+
+# (B, T, d, V, block_v, tmax, group): each case runs the kernel with every
+# slot in its own group (one head pass per slot) and with `group` slots
+# folded into the rows of one matmul.
+GROUPING_CASES = {
+    "batch_not_multiple_of_group": (3, 8, 16, 256, None, [1.0, 1.0, 1.0], 2),
+    "tree_padded_6_to_8": (4, 6, 16, 256, None, [1.0] * 4, 4),
+    "ragged_vocab_major": (2, 4, 128, 300, 128, [1.0, 0.7], 2),
+    "per_slot_temperatures": (3, 8, 16, 512, 128, [1.0, 0.7, 1e-6], 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPING_CASES))
+def test_verify_stats_grouping_is_bitwise_neutral(rng, case):
+    """Folding G slots into the kernel's rows changes how often the head
+    is read, not a statistic: argm, m and cand_w are bitwise those of one
+    slot per group, l within the online rescale's 1 ulp."""
+    B, T, d, Vc, block_v, temps, group = GROUPING_CASES[case]
+    hidden = jnp.asarray(rng.standard_normal((B, T, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((d, Vc)), jnp.float32) * 0.3
+    cand = jnp.asarray(rng.integers(0, Vc, (B, T)), jnp.int32)
+    tmax = jnp.asarray(temps, jnp.float32)
+    Tp = T + (-T % 8)
+    plans = [TA.verify_stats_plan(B, Tp, d, block_v or Vc, jnp.float32,
+                                  max_group=g)[0] for g in (1, group)]
+    assert plans == [1, group]
+    one, grouped = (TA.unembed_verify_stats(
+        hidden, w, cand, tmax, block_v=block_v, max_group=g, interpret=True)
+        for g in (1, group))
+    for i, name in ((0, "argm"), (1, "m"), (3, "cand_w")):
+        np.testing.assert_array_equal(np.asarray(one[i]),
+                                      np.asarray(grouped[i]), name)
+    np.testing.assert_array_max_ulp(np.asarray(one[2]),
+                                    np.asarray(grouped[2]), maxulp=1)
+    assert one[0].shape == (B, T) and one[3].shape == (B, T, T)
+
+
+@pytest.mark.parametrize("B", [4, 8])
+def test_verify_stats_plan_one_head_pass_at_cell_shapes(B):
+    """openpangu-7b's decode step (B slots x a 64-node tree, d 4096,
+    V 153,376, bf16): every slot fits one group, so one head pass."""
+    G, block_v, need = TA.verify_stats_plan(B, 64, 4096, 512, jnp.bfloat16)
+    assert (G, block_v) == (B, 512)
+    assert need <= TA.VERIFY_VMEM_LIMIT
+    assert TA.lm_head_passes(B, 64, 4096, 153376, jnp.bfloat16) == 1
+
+
+def test_verify_stats_plan_splits_when_vmem_is_short():
+    """32 slots do not fit one group: the plan splits them into even
+    groups that fit, and G = 1 stays reachable."""
+    G, _, need = TA.verify_stats_plan(32, 64, 4096, 512, jnp.bfloat16)
+    assert 1 < G < 32 and 32 % G == 0
+    assert need <= TA.VERIFY_VMEM_LIMIT
+    assert TA.lm_head_passes(32, 64, 4096, 153376, jnp.bfloat16) == 32 // G
+    assert TA.verify_stats_plan(32, 64, 4096, 512, jnp.bfloat16,
+                                max_group=1)[0] == 1
 
 
 # ----------------------------------------------- walk differential (no E2E)
