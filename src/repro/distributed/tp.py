@@ -25,6 +25,11 @@ Proposer state, PRNG keys and every replicated input stay bit-identical
 across shards by determinism, so the wrapped step runs with
 ``check=False`` and replicated out_specs — the same discipline as
 ``collectives.ag_matmul``.
+
+Tracing (DESIGN.md §18): every wrapped program compiles under a stable
+name (``jit_tp_prefill``, ``jit_tp_prefill_logits``, ``jit_tp_spec_step``,
+``jit_tp_generate``), each call runs inside a ``tp.*`` profiler span, and
+``stats`` counts the calls on the host.
 """
 from __future__ import annotations
 
@@ -33,6 +38,7 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, SamplingParams
@@ -42,6 +48,7 @@ from repro.distributed import profiles
 from repro.distributed.collectives import shard_map_compat
 from repro.distributed.sharding import spec_for
 from repro.models import api as model_api
+from repro.serving.scheduler import named
 
 _TP_PROPOSERS = ("medusa", "ngram")
 
@@ -96,6 +103,14 @@ class TPSpecEngine:
     single-device engine.  Outputs are replicated (every shard computes
     the same tokens/verdicts by determinism); the cache stays sharded on
     its kv-head axis across calls.
+
+    ``stats`` (host counters, no device sync on the call path):
+    ``prefill_calls``, ``spec_step_calls``, ``generate_calls``;
+    ``generate_steps``, the decode steps ``generate`` reported, read from
+    its returned ``StepStats`` when ``stats`` is read; ``psums_per_step``,
+    the plan's row-parallel exchanges in one speculative step (one psum
+    after attention ``wo`` and one after the MLP down-projection per
+    layer: 2 x ``num_layers``).
     """
 
     def __init__(self, cfg: ModelConfig, mesh: Mesh, *, axis: str = "model",
@@ -123,6 +138,20 @@ class TPSpecEngine:
         self.sampling = self.local.sampling
         self._pspecs = None
         self._fns = {}
+        self._counts = {"prefill_calls": 0, "spec_step_calls": 0,
+                        "generate_calls": 0, "generate_steps": 0,
+                        "psums_per_step": 2 * cfg.num_layers}
+        self._pending_steps = []    # StepStats.steps of generate, on device
+
+    @property
+    def stats(self) -> dict:
+        """The host counters (class docstring); folds in the step counts of
+        finished ``generate`` calls, whose results the caller has read."""
+        if self._pending_steps:
+            self._counts["generate_steps"] += sum(
+                int(n) for n in jax.device_get(self._pending_steps))
+            self._pending_steps.clear()
+        return dict(self._counts)
 
     # ------------------------------------------------------------ placement
 
@@ -191,6 +220,12 @@ class TPSpecEngine:
             fn = self._fns[name] = build()
         return fn
 
+    def _sharded(self, name, fn, in_specs, out_specs):
+        """``fn`` under shard_map on the mesh, jitted as ``jit_<name>``."""
+        return jax.jit(shard_map_compat(
+            named(fn, name), mesh=self.mesh, in_specs=in_specs,
+            out_specs=out_specs, check=False))
+
     def prefill(self, params, proposer_params, tokens, lengths, cache,
                 key=None, state=None):
         pspecs, eng = self._require_specs(), self.local
@@ -201,13 +236,15 @@ class TPSpecEngine:
             def fn(params, pp, tokens, lengths, cache, key, state):
                 return eng.prefill(params, pp, tokens, lengths, cache,
                                    key=key, state=state)
-            return jax.jit(shard_map_compat(
-                fn, mesh=self.mesh,
-                in_specs=(pspecs, P(), P(), P(), cspec, P(), P()),
-                out_specs=(cspec, P(), P(), P()), check=False))
+            return self._sharded(
+                "tp_prefill", fn, (pspecs, P(), P(), P(), cspec, P(), P()),
+                (cspec, P(), P(), P()))
 
-        return self._cached("prefill", build)(
-            params, proposer_params, tokens, lengths, cache, key, state)
+        fn = self._cached("prefill", build)
+        self._counts["prefill_calls"] += 1
+        with TraceAnnotation("tp.prefill", batch=int(tokens.shape[0])):
+            return fn(params, proposer_params, tokens, lengths, cache, key,
+                      state)
 
     def prefill_logits(self, params, tokens, lengths, cache):
         """[B, V] next-token logits after each prompt, replicated — the
@@ -222,12 +259,13 @@ class TPSpecEngine:
                 h, _ = local.model.prefill(params, local.cfg, tokens,
                                            lengths, cache)
                 return local.model.unembed(params, local.cfg, h)
-            return jax.jit(shard_map_compat(
-                logits_fn, mesh=self.mesh, in_specs=(pspecs, P(), P(), cspec),
-                out_specs=P(), check=False))
+            return self._sharded("tp_prefill_logits", logits_fn,
+                                 (pspecs, P(), P(), cspec), P())
 
-        return self._cached("prefill_logits", build)(params, tokens, lengths,
-                                                     cache)
+        fn = self._cached("prefill_logits", build)
+        self._counts["prefill_calls"] += 1
+        with TraceAnnotation("tp.prefill", batch=int(tokens.shape[0])):
+            return fn(params, tokens, lengths, cache)
 
     def spec_step(self, params, proposer_params, cache, lengths, base, state,
                   key):
@@ -239,13 +277,15 @@ class TPSpecEngine:
             def fn(params, pp, cache, lengths, base, state, key):
                 return eng.spec_step(params, pp, cache, lengths, base,
                                      state, key)
-            return jax.jit(shard_map_compat(
-                fn, mesh=self.mesh,
-                in_specs=(pspecs, P(), cspec, P(), P(), P(), P()),
-                out_specs=(cspec, P(), P(), P()), check=False))
+            return self._sharded(
+                "tp_spec_step", fn, (pspecs, P(), cspec, P(), P(), P(), P()),
+                (cspec, P(), P(), P()))
 
-        return self._cached("spec_step", build)(
-            params, proposer_params, cache, lengths, base, state, key)
+        fn = self._cached("spec_step", build)
+        self._counts["spec_step_calls"] += 1
+        with TraceAnnotation("tp.spec_step", batch=int(lengths.shape[0])):
+            return fn(params, proposer_params, cache, lengths, base, state,
+                      key)
 
     def generate(self, params, proposer_params, tokens, prompt_lengths,
                  cache, max_new: int, key=None, state=None):
@@ -257,14 +297,18 @@ class TPSpecEngine:
             def fn(params, pp, tokens, plens, cache, key, state):
                 return eng.generate(params, pp, tokens, plens, cache,
                                     max_new, key=key, state=state)
-            return jax.jit(shard_map_compat(
-                fn, mesh=self.mesh,
-                in_specs=(pspecs, P(), P(), P(), cspec, P(), P()),
-                out_specs=P(), check=False))
+            return self._sharded(
+                "tp_generate", fn, (pspecs, P(), P(), P(), cspec, P(), P()),
+                P())
 
-        return self._cached(("generate", int(max_new)), build)(
-            params, proposer_params, tokens, prompt_lengths, cache, key,
-            state)
+        fn = self._cached(("generate", int(max_new)), build)
+        self._counts["generate_calls"] += 1
+        with TraceAnnotation("tp.generate", batch=int(tokens.shape[0]),
+                             max_new=int(max_new)):
+            out = fn(params, proposer_params, tokens, prompt_lengths, cache,
+                     key, state)
+        self._pending_steps.append(out[2].steps)
+        return out
 
 
 def build_tp_engine(cfg: ModelConfig, mesh: Mesh, proposer: str = "medusa",

@@ -141,7 +141,9 @@ def tp_generate(cfg, prompts, *, tp: int, data: int = 1,
 
 
 def serve_tp(args, cfg, sampling):
-    """--tp path: ``tp_generate`` over the launcher's random prompts."""
+    """--tp path: ``tp_generate`` over the launcher's random prompts; with
+    ``--profile`` under the profiler (the engine's ``tp.*`` spans and
+    ``jit_tp_*`` programs, DESIGN.md §18)."""
     if args.mesh_shape:
         try:
             d, m = (int(x) for x in args.mesh_shape.lower().split("x"))
@@ -153,11 +155,13 @@ def serve_tp(args, cfg, sampling):
     else:
         d, m = 1, args.tp
     prompts = random_prompts(cfg, args.requests)
-    outs, dt = tp_generate(cfg, prompts, tp=m, data=d,
-                           proposer=args.proposer, gamma=args.gamma,
-                           accept=args.accept, sampling=sampling,
-                           slots=args.slots, max_len=args.max_len,
-                           max_new=args.max_new)
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        outs, dt = tp_generate(cfg, prompts, tp=m, data=d,
+                               proposer=args.proposer, gamma=args.gamma,
+                               accept=args.accept, sampling=sampling,
+                               slots=args.slots, max_len=args.max_len,
+                               max_new=args.max_new)
     toks = sum(len(o) for o in outs)
     print(f"tp={args.tp} mesh=({d}x{m}) proposer={args.proposer}: "
           f"{len(prompts)} requests, {toks} tokens in {dt:.1f}s "
@@ -170,7 +174,7 @@ def device_label() -> str:
     return f"{dev.platform} {dev.device_kind}"
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="openpangu-7b", choices=ALL_ARCHS)
     ap.add_argument("--published-widths", action="store_true",
@@ -260,9 +264,14 @@ def main():
                          "'2x4'); default '1x<tp>'")
     ap.add_argument("--profile", default="", metavar="DIR",
                     help="write a profiler trace of the serving loop to DIR: "
-                         "the scheduler's serve.* spans and the named "
-                         "programs on the device's clock (README, Tracing)")
-    args = ap.parse_args()
+                         "the scheduler's serve.* spans (with --tp the "
+                         "engine's tp.* spans) and the named programs on "
+                         "the device's clock (README, Tracing)")
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
     enable_compile_cache()
 
     fields = {}
@@ -279,9 +288,6 @@ def main():
                             adaptive_gamma=args.adaptive_gamma)
     kinds = [k.strip() for k in args.families.split(",") if k.strip()]
     if args.tp:
-        if args.profile:
-            raise SystemExit("--profile traces the scheduler's serving loop; "
-                             "--tp serves static batches without it")
         if kinds or args.replicas:
             raise SystemExit("--tp serves static batches through the sharded "
                              "engine; it does not combine with --families "

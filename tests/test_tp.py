@@ -161,6 +161,26 @@ def test_tp_engine_requires_shard_params_first():
         tpe.prefill(None, None, None, None, {})
 
 
+def test_tp_engine_stats_before_any_call():
+    from repro.distributed.tp import build_tp_engine
+    cfg = _cfg()
+    tpe = build_tp_engine(cfg, FakeMesh(), "ngram")
+    assert tpe.stats == {"prefill_calls": 0, "spec_step_calls": 0,
+                         "generate_calls": 0, "generate_steps": 0,
+                         "psums_per_step": 2 * cfg.num_layers}
+
+
+def test_serve_parses_tp_with_profile():
+    """``--tp N --profile DIR`` is one launch: the sharded path under the
+    profiler (the trace itself: tests/bench/test_bench_tp4.py)."""
+    from repro.launch.serve import build_parser
+    args = build_parser().parse_args(["--tp", "4", "--profile", "/tmp/t",
+                                      "--mesh-shape", "1x4"])
+    assert (args.tp, args.profile, args.mesh_shape) == (4, "/tmp/t", "1x4")
+    args = build_parser().parse_args([])
+    assert (args.tp, args.profile) == (0, "")
+
+
 # ------------------------------------------------------ ngram matcher index
 
 def _primed(matcher, rng, B=3, cap=96):
