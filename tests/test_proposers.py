@@ -171,6 +171,45 @@ def test_golden_tokens_draft(stack, suffix, layout, dtype):
 
 
 # ---------------------------------------------------------------------------
+# Medusa top-k: the k-pass selection == a full top-k and softmax
+# ---------------------------------------------------------------------------
+
+def _full_topk(logits, k):
+    """The reference: ``lax.top_k`` over the vocabulary, and the softmax
+    over it gathered at those ids."""
+    x = logits.astype(jnp.float32)
+    idx = jax.lax.top_k(x, k)[1]
+    return idx, jnp.take_along_axis(jax.nn.softmax(x, -1), idx, -1)
+
+
+@pytest.mark.parametrize("k,heads,batch,vocab,dtype", [
+    (1, 1, 1, 1000, "bfloat16"), (3, 4, 8, 1000, "bfloat16"),
+    (10, 4, 8, 1000, "bfloat16"), (10, 2, 1, 4099, "bfloat16"),
+    (10, 4, 8, 1000, "float32")])
+def test_medusa_topk_matches_full_topk(k, heads, batch, vocab, dtype):
+    """Same ids as ``lax.top_k``, ties broken toward the lower id, and the
+    same probabilities to within 1 ulp.  Zero resblocks and one-hot hidden
+    states make each head's logits exactly rows of its ``lm``: integers in
+    [-6, 6], so ties are everywhere, and one row whose maximum sits at
+    scattered ids."""
+    dt = jnp.dtype(dtype)
+    rng = np.random.default_rng(k * 1000 + vocab)
+    lm = np.round(rng.normal(size=(heads, batch, vocab)) * 2).clip(-6, 6)
+    lm[0, 0, rng.choice(vocab, 2 * k, replace=False)] = 9.0
+    mp = {"w1": jnp.zeros((heads, batch, batch), dt),
+          "b1": jnp.zeros((heads, batch), dt), "lm": jnp.asarray(lm, dt)}
+    hidden = jnp.eye(batch, dtype=dt)
+    idx, probs = jax.jit(M.medusa_topk, static_argnums=2)(mp, hidden, k)
+    ridx, rprobs = _full_topk(M.medusa_logits(mp, hidden), k)
+    assert idx.dtype == jnp.int32 and probs.dtype == jnp.float32
+    assert idx.shape == probs.shape == (heads, batch, k)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
+    ulps = np.abs(np.asarray(probs).view(np.int32)
+                  - np.asarray(rprobs).view(np.int32))
+    assert ulps.max() <= 1, ulps.max()
+
+
+# ---------------------------------------------------------------------------
 # n-gram proposer units
 # ---------------------------------------------------------------------------
 

@@ -141,8 +141,28 @@ def test_unembed_verify_stats_compiles_without_head_copy(one_chip, slots):
     assert temp < 64 << 20, f"{temp} bytes of scratch: the lm head is copied"
 
 
+@pytest.mark.parametrize("slots", [8, 16])
+def test_medusa_topk_compiles_without_sort(one_chip, slots):
+    """The four Medusa heads at published widths ([4, slots, 4096] head
+    hidden states into [4, 4096, 153376] projections) and the
+    ``medusa_63`` tree's k = 10, at the one-chip cells' 8 slots and the
+    TP cell's 16: the top-k is reduces over the vocabulary, with no sort
+    and no TopK custom call."""
+    from repro.core import medusa as M
+
+    def sds(s):
+        return jax.ShapeDtypeStruct(s, BF16, sharding=one_chip)
+
+    mp = {"w1": sds((4, D_MODEL, D_MODEL)), "b1": sds((4, D_MODEL)),
+          "lm": sds((4, D_MODEL, VOCAB))}
+    text = jax.jit(M.medusa_topk, static_argnums=2).lower(
+        mp, sds((slots, D_MODEL)), 10).compile().as_text()
+    assert " sort(" not in text
+    assert 'custom_call_target="TopK"' not in text
+
+
 @pytest.fixture(scope="module")
-def mesh_2x2(topo, one_chip):       # one_chip: the compilation cache is off
+def mesh_2x2(topo, one_chip):      # one_chip: the compilation cache is off
     return Mesh(np.asarray(topo.devices[:4]).reshape(1, 4), ("data", "model"))
 
 
